@@ -12,6 +12,8 @@ import sys
 import time
 import traceback
 
+from repro.utils.compile_cache import enable_compile_cache
+
 SUITES = {
     "fig7": ("benchmarks.bench_recall", "Fig. 7 recall/time vs baselines"),
     "fig8": ("benchmarks.bench_index_build", "Fig. 8 index construction"),
@@ -34,6 +36,7 @@ SUITES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="all",
                     help="comma-separated suite names (default: all)")

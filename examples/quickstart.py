@@ -12,10 +12,12 @@ import numpy as np
 from repro.baselines import exact_knn, recall
 from repro.core import build_index, knn_query
 from repro.data import make_dataset, make_queries
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.config import ClimberConfig
 
 
 def main():
+    enable_compile_cache()
     cfg = ClimberConfig(
         series_len=256,        # n  — raw readings per series
         paa_segments=16,       # w  — PAA word length

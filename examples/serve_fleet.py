@@ -12,13 +12,13 @@ answers on the same contents are unchanged.
 ``--mesh`` attaches a data-axis mesh over every local device, so sealed
 shards execute mesh-resident (one shard_map fan-out instead of the
 per-shard host loop) — and the example asserts the two placements return
-bit-identical answers.
+the same answers (identical gids, distances to float32 rounding).
 
 ``--storage DIR`` (default: a temp dir) attaches the lifecycle plane's
 durable storage: inserts append to the write-ahead log before the delta
 scatter, ``save()`` snapshots the sealed shards, and the example simulates
 a crash — ``IndexFleet.open`` replays the WAL tail and the restored
-answers are asserted bit-identical.  Step-by-step commentary:
+answers are asserted unchanged.  Step-by-step commentary:
 docs/SERVING.md.
 """
 import argparse
@@ -28,13 +28,18 @@ import jax
 import numpy as np
 
 from repro.data import make_dataset, make_queries
+from repro.eval.metrics import assert_same_answers
 from repro.fleet import FleetConfig, FleetEngine, IndexFleet
 from repro.launch.mesh import make_mesh
 from repro.serve import api
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.config import ClimberConfig
+
+SCALE = 2 * 128     # bound on |q|^2 + |x|^2 for z-normalised length 128
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--shards", type=int, default=3)
     ap.add_argument("--batch-size", type=int, default=8)
@@ -97,9 +102,9 @@ def main():
     if args.mesh:
         dh, gh, _ = fleet.query(queries, 10, placement="host")
         dm, gm, _ = fleet.query(queries, 10, placement="mesh")
-        assert np.array_equal(gh, gm) and np.array_equal(dh, dm)
-        print("mesh placement: one shard_map fan-out, answers bit-identical "
-              "to the host loop")
+        assert_same_answers(dh, gh, dm, gm, scale=SCALE)
+        print("mesh placement: one shard_map fan-out, same answers as the "
+              "host loop (identical gids, distances to float32 rounding)")
 
     # restart durability: "crash" the process state and replay the WAL —
     # the delta was never snapshotted, yet answers come back bit-identical
@@ -109,10 +114,10 @@ def main():
     restored = IndexFleet.open(storage)
     dr, gr, _ = restored.query(queries, 10, routing="exhaustive",
                                variant="exhaustive")
-    assert np.array_equal(g1, gr) and np.array_equal(d1, dr)
+    assert_same_answers(d1, g1, dr, gr, scale=SCALE)
     print(f"restart: WAL tail replayed "
           f"({restored.delta.occupancy} delta records), answers "
-          f"bit-identical")
+          f"unchanged")
 
     # compaction seals the delta on the worker thread; answers on the same
     # contents don't move, and the WAL segment is truncated once the shard
@@ -120,7 +125,7 @@ def main():
     fleet.compact()
     d2, g2, _ = fleet.query(queries, 10, routing="exhaustive",
                             variant="exhaustive")
-    assert np.array_equal(g1, g2) and np.array_equal(d1, d2)
+    assert_same_answers(d1, g1, d2, g2, scale=SCALE)
     print(f"compact(): sealed into {fleet.shards[-1].key}; "
           f"answers unchanged")
 

@@ -13,10 +13,12 @@ import numpy as np
 from repro.core import build_index
 from repro.data import make_dataset, make_queries
 from repro.serve import ClimberEngine, api
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.config import ClimberConfig
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch-size", type=int, default=8)
     ap.add_argument("--requests", type=int, default=24)

@@ -56,7 +56,6 @@ def exact_knn(queries: jnp.ndarray, data: jnp.ndarray, k: int,
 def exact_knn_sharded(queries: jnp.ndarray, data: jnp.ndarray, k: int,
                       *, mesh, data_axis: str = "data"):
     """Mesh version: records sharded over ``data_axis``, queries replicated."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(q, x):
@@ -71,8 +70,8 @@ def exact_knn_sharded(queries: jnp.ndarray, data: jnp.ndarray, k: int,
         neg2, pos = jax.lax.top_k(-d, k)
         return jnp.sqrt(jnp.maximum(-neg2, 0.0)), jnp.take_along_axis(i, pos, -1)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P(), P(data_axis)),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(), P(data_axis)),
+                       out_specs=(P(), P()), check_vma=False)
     return fn(queries, data)
 
 

@@ -5,6 +5,7 @@ rows so they vectorise over millions of objects and shard cleanly under pjit.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -20,7 +21,8 @@ def squared_l2_pairwise(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     """
     x2 = jnp.sum(x * x, axis=-1)[:, None]
     y2 = jnp.sum(y * y, axis=-1)[None, :]
-    return jnp.maximum(x2 - 2.0 * (x @ y.T) + y2, 0.0)
+    xy = jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(x2 - 2.0 * xy + y2, 0.0)
 
 
 def overlap_distance(x_onehot: jnp.ndarray, c_onehot: jnp.ndarray,
@@ -53,4 +55,5 @@ def weight_distance(x_weighted: jnp.ndarray, c_onehot: jnp.ndarray,
     Returns:
       ``[..., G]``.
     """
-    return tw - x_weighted @ c_onehot.T
+    return tw - jnp.matmul(x_weighted, c_onehot.T,
+                           precision=jax.lax.Precision.HIGHEST)

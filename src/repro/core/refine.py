@@ -125,7 +125,8 @@ def _masked_distances(store: PartitionStore, queries: jnp.ndarray,
     rdfs = store.rec_dfs[pid]
     rgid = store.rec_gid[pid]
 
-    dots = jnp.einsum("qn,qmcn->qmc", queries, rows)
+    dots = jnp.einsum("qn,qmcn->qmc", queries, rows,
+                      precision=jax.lax.Precision.HIGHEST)
     d2 = jnp.maximum(q2[:, None, None] - 2.0 * dots + rows2, 0.0)
 
     valid = rgid >= 0
@@ -257,7 +258,6 @@ def refine_sharded(store: PartitionStore, queries: jnp.ndarray,
     streaming fused kernel over its local shard, so the per-device top-k is
     produced without materializing any local distance tensor either.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     use_kernel = resolve_use_kernel(use_kernel)
@@ -287,12 +287,12 @@ def refine_sharded(store: PartitionStore, queries: jnp.ndarray,
         # pad answers carry the same sentinel as the dense path (sqrt(_INF))
         return jnp.where(g_top >= 0, -neg, jnp.sqrt(_INF)), g_top
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(data_axis), P(data_axis), P(data_axis), P(data_axis),
                   P(data_axis), P(), P(), P(), P()),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
     return fn(store.data, store.norms, store.rec_dfs, store.rec_gid,
               store.count, queries, sel_part, sel_lo, sel_hi)
 

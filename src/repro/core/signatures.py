@@ -25,10 +25,11 @@ def pivot_distances(paa: jnp.ndarray, pivots: jnp.ndarray) -> jnp.ndarray:
     Returns:
       ``[..., r]`` squared distances (monotone in ED — ranking-equivalent).
     """
-    # |a-b|^2 = |a|^2 - 2ab + |b|^2 ; the -2ab term is the MXU-friendly matmul.
+    # |a-b|^2 = |a|^2 - 2ab + |b|^2 ; the -2ab term is the MXU-friendly matmul,
+    # at full f32 precision: the ranking decides P4-> and so the partitions.
     a2 = jnp.sum(paa * paa, axis=-1, keepdims=True)
     b2 = jnp.sum(pivots * pivots, axis=-1)
-    ab = paa @ pivots.T
+    ab = jnp.matmul(paa, pivots.T, precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(a2 - 2.0 * ab + b2, 0.0)
 
 

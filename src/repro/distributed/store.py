@@ -25,12 +25,11 @@ The same helpers serve two layouts:
   * **partition-sharded** (single index): each field's leading axis is P,
     so every device scans a slice of one index's partitions
     (``refine_sharded``);
-  * **shard-stacked** (fleet): :func:`stack_stores` stacks whole shard
-    stores on a NEW leading shard axis ``S`` (ragged P/cap padded with
-    inert slots, local gids remapped to fleet-global), after which
-    ``pad_store``/``store_pspecs``/``shard_store`` apply verbatim to the
-    shard axis — each device then owns whole indexes, which is how the
-    fleet's mesh placement (``repro.fleet.placement``) lays a fleet out.
+  * **shard-stacked** (fleet): the fleet's mesh placement
+    (``repro.fleet.placement``) stacks whole shard stores on a NEW leading
+    shard axis ``S`` (ragged P/cap padded with inert slots, local gids
+    remapped to fleet-global) and shards that axis — each device then
+    owns whole indexes.
     The trie skeletons ride the same layout through the sibling helper
     :func:`repro.fleet.device_plan.stack_tries` (``[S, ...]`` padded trie
     tables next to the ``[S, ...]`` stacked stores), which is what lets the
@@ -88,34 +87,6 @@ def _pad_caps(store: PartitionStore, cap: int,
     return PartitionStore(
         data=slot(store.data), norms=slot(store.norms),
         rec_dfs=slot(store.rec_dfs, -1), rec_gid=gid, count=store.count)
-
-
-def stack_stores(stores, gid_maps=None) -> PartitionStore:
-    """Stack shard stores on a NEW leading shard axis (``S`` first).
-
-    Every field becomes ``[S, ...]`` — ``data [S, P, cap, n]``, ``count
-    [S, P]`` — with ragged partition counts and slot capacities padded to
-    the fleet-wide maxima using inert slots (``rec_gid = rec_dfs = -1``,
-    never inside a node interval, never a live record).  This is the
-    layout the fleet's mesh placement shards over the data axis: device d
-    holds whole shards ``[d·per, (d+1)·per)``, and ``pad_store`` /
-    ``store_pspecs`` apply to the shard axis unchanged.
-
-    Args:
-      stores: sequence of PartitionStore (same series_len).
-      gid_maps: optional per-store ``[n_i]`` arrays mapping each store's
-        local record ids to fleet-global ids; identity when omitted.
-    """
-    stores = list(stores)
-    if not stores:
-        raise ValueError("stack_stores needs at least one store")
-    cap = max(s.capacity for s in stores)
-    pmax = max(s.num_partitions for s in stores)
-    padded = []
-    for i, s in enumerate(stores):
-        s = _pad_caps(s, cap, None if gid_maps is None else gid_maps[i])
-        padded.append(pad_store(s, pmax) if s.num_partitions < pmax else s)
-    return PartitionStore(*[jnp.stack(x) for x in zip(*padded)])
 
 
 def concat_stores(stores, gid_maps=None) -> PartitionStore:

@@ -16,11 +16,64 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["recall_at_k", "mean_average_precision", "frontier_auc"]
+__all__ = ["recall_at_k", "mean_average_precision", "frontier_auc",
+           "answers_agree", "assert_same_answers", "distance_error",
+           "DIST_ULPS"]
+
+# Distances are sqrt(max(‖q‖² − 2q·x + ‖x‖², 0)) in float32: two paths that
+# sum the dot in a different order (batch shape, placement, kernel blocking,
+# device) agree on the squared distance only to the rounding of its largest
+# term, ‖q‖² + ‖x‖².  DIST_ULPS float32 ulps of that bound is the stated
+# tolerance; near a self-match it is the whole difference (sqrt of the
+# cancellation noise), elsewhere it is far below 1e-4.
+DIST_ULPS = 16
 
 
 def _valid(ids: np.ndarray) -> np.ndarray:
     return ids[ids >= 0]
+
+
+def answers_agree(dist_a: np.ndarray, gid_a: np.ndarray,
+                  dist_b: np.ndarray, gid_b: np.ndarray, *, scale: float,
+                  ulps: int = DIST_ULPS) -> Tuple[bool, str]:
+    """Whether two exact-ED answers are the same answer.
+
+    Gids must be identical, order and pads included.  Squared distances
+    may differ by ``ulps`` float32 ulps of ``scale`` — a bound on
+    ‖q‖² + ‖x‖² (``2 * series_len`` for z-normalised series) — which is
+    the rounding of the ‖q‖² − 2q·x + ‖x‖² formulation (see
+    :data:`DIST_ULPS`).  Pad slots carry the same sentinel on both sides,
+    so they compare equal.  Returns ``(ok, reason)``.
+    """
+    ga, gb = np.asarray(gid_a), np.asarray(gid_b)
+    if ga.shape != gb.shape or not np.array_equal(ga, gb):
+        bad = int(np.sum(ga != gb)) if ga.shape == gb.shape else -1
+        return False, f"gids differ ({bad} slots of {ga.size})"
+    err, tol = distance_error(dist_a, dist_b, scale=scale, ulps=ulps)
+    if err > tol:
+        return False, f"squared distances differ by {err:.3e} > {tol:.3e}"
+    return True, "ok"
+
+
+def distance_error(dist_a: np.ndarray, dist_b: np.ndarray, *, scale: float,
+                   ulps: int = DIST_ULPS) -> Tuple[float, float]:
+    """``(max |d_a² − d_b²|, ulps·eps32·scale)`` over matching slots — the
+    distance half of :func:`answers_agree`, usable where gids may differ
+    at exact ties (a float64 reference against a float32 answer)."""
+    da = np.asarray(dist_a, np.float64)
+    db = np.asarray(dist_b, np.float64)
+    err = np.abs(da * da - db * db)
+    tol = ulps * float(np.finfo(np.float32).eps) * float(scale)
+    return (float(err.max()) if err.size else 0.0), tol
+
+
+def assert_same_answers(dist_a, gid_a, dist_b, gid_b, *, scale: float,
+                        ulps: int = DIST_ULPS) -> None:
+    """:func:`answers_agree`, raising ``AssertionError`` with the reason."""
+    ok, why = answers_agree(dist_a, gid_a, dist_b, gid_b, scale=scale,
+                            ulps=ulps)
+    if not ok:
+        raise AssertionError(why)
 
 
 def recall_at_k(approx_ids: np.ndarray, exact_ids: np.ndarray,

@@ -8,8 +8,8 @@ on the mesh next to its partition store.  Shards are ragged (different node
 fleet-wide maxima with *inert* entries (:func:`repro.core.traversal.pad_trie`
 — int32-max edge keys that no probe can match, an inert node with an empty
 DFS interval and no partitions, pad groups rooted at it) and stacked on a
-new leading shard axis — the exact trie analogue of
-:func:`repro.distributed.store.stack_stores`:
+new leading shard axis — the trie analogue of the mesh placement's
+stacked stores (``repro.fleet.placement``):
 
   * :func:`stack_tries`    — ``[TrieDevice] → TrieTables [S, ...]`` (+ pad
     shards up to a mesh-divisible slot count, mirroring ``pad_store``);

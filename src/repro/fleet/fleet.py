@@ -697,17 +697,32 @@ class IndexFleet:
                 sample[: max(4 * self.cfg.shard_cfg.num_pivots, 256)],
                 self.cfg.shard_cfg)
 
-    def _build_shard_index(self, data: np.ndarray, fold: int) -> ClimberIndex:
-        """Deterministic INX build for a fleet member (no lock needed)."""
+    def _build_shard_index(self, data: np.ndarray, fold: int,
+                           device=None) -> ClimberIndex:
+        """Deterministic INX build for a fleet member (no lock needed).
+
+        The build runs on JAX's default device, so every shard of one shape
+        reuses its compiled programs (a TPU compiles per device); the
+        finished store then moves to ``device`` when one is given (the
+        small skeleton arrays stay uncommitted and follow it into jits).
+        """
         build_key = jax.random.fold_in(
             jax.random.PRNGKey(self.cfg.seed), fold)
-        return build_index(build_key, jnp.asarray(data), self.cfg.shard_cfg)
+        index = build_index(build_key, jnp.asarray(data), self.cfg.shard_cfg)
+        if device is None:
+            return index
+        return dataclasses.replace(index,
+                                   store=jax.device_put(index.store, device))
 
     def add_shard(self, key: str, data: np.ndarray,
-                  global_ids: Optional[np.ndarray] = None) -> ShardHandle:
+                  global_ids: Optional[np.ndarray] = None, *,
+                  device=None) -> ShardHandle:
         """Build and register an immutable shard over ``data``.
 
         ``global_ids`` defaults to the next contiguous fleet-global range.
+        ``device`` (default: JAX's default device) is where the shard's
+        index is kept — a fleet larger than one chip gives each shard the
+        chip its mesh slot lives on, so no chip holds the others' stores.
         """
         data = np.asarray(data, dtype=np.float32)
         with self._lock:
@@ -722,7 +737,7 @@ class IndexFleet:
                 self._next_gid = max(self._next_gid,
                                      int(global_ids.max()) + 1)
             fold = len(self.shards) + 17
-        index = self._build_shard_index(data, fold)
+        index = self._build_shard_index(data, fold, device)
         handle = ShardHandle(key=key, index=index, global_ids=global_ids,
                              created_at=time.time())
         with self._lock:

@@ -13,16 +13,16 @@ instead:
   * every sealed shard's :class:`~repro.core.index.PartitionStore` is
     stacked on a new leading shard axis (ragged partition counts / slot
     capacities padded with inert ``rec_gid = -1`` slots, local record ids
-    remapped to fleet-global ids at stack time) via
-    :func:`repro.distributed.store.stack_stores`;
+    remapped to fleet-global ids at stack time), one device slab at a time
+    (:func:`_lay_out_stores`), so no device ever holds more than its own
+    shards;
   * every sealed shard's trie skeleton, pivot set and centroid table are
     stacked the same way (:func:`repro.fleet.device_plan.stack_tries` —
     ragged node/edge/group counts padded with inert entries that can never
     match a probe or contribute a partition);
   * the shard axis is padded to a multiple of the mesh's data-axis size
-    (``pad_store`` / all-inert pad tries — a pad shard is a no-op under
-    ``merge_topk``) and laid out with
-    :func:`repro.distributed.store.store_pspecs`, so device d owns whole
+    (empty pad shards / all-inert pad tries — a pad shard is a no-op under
+    ``merge_topk``) and sharded over the data axis, so device d owns whole
     shards ``[d·per, (d+1)·per)``;
   * :meth:`query` then runs the WHOLE query — featurize → trie descent →
     plan → budgeted compaction → refine → merge — as ONE jitted shard_map:
@@ -64,8 +64,67 @@ from repro.core.query import (QueryPlan, ShardPlanContext, candidates_scanned,
                               get_device_planner, get_planner)
 from repro.core.refine import (PAD_DIST, merge_topk, refine,
                                resolve_use_kernel)
-from repro.distributed.store import pad_store, stack_stores, store_pspecs
 from repro.fleet.device_plan import ShardView, stack_tries, trie_row
+
+
+def _lay_out_stores(mesh, data_axis: str, shards,
+                    s_pad: int) -> PartitionStore:
+    """The sealed stores as one ``[S_pad, P, cap, ...]`` stack sharded over
+    ``data_axis``, built one device slab at a time.
+
+    Each device's slab — its whole shards, ragged P/cap padded with inert
+    slots (``rec_dfs = rec_gid = -1``, zero rows and norms), local record
+    ids remapped to fleet-global ids, pad shards empty — is assembled on
+    the host and copied to that device alone, so no device ever holds the
+    whole fleet (or a second copy of it) on the way.
+    """
+    stores = [s.index.store for s in shards]
+    pmax = max(st.num_partitions for st in stores)
+    cap = max(st.capacity for st in stores)
+    n = stores[0].data.shape[-1]
+    sharding = NamedSharding(mesh, PS(data_axis))
+    pieces = [[] for _ in PartitionStore._fields]
+    for dev, idx in sharding.addressable_devices_indices_map(
+            (s_pad,)).items():
+        lo, hi, _ = idx[0].indices(s_pad)
+        m = hi - lo
+        slab = PartitionStore(
+            data=np.zeros((m, pmax, cap, n), stores[0].data.dtype),
+            norms=np.zeros((m, pmax, cap), stores[0].norms.dtype),
+            rec_dfs=np.full((m, pmax, cap), -1, np.int32),
+            rec_gid=np.full((m, pmax, cap), -1, np.int32),
+            count=np.zeros((m, pmax), np.int32))
+        for j in range(lo, min(hi, len(shards))):
+            st, p, c = stores[j], stores[j].num_partitions, stores[j].capacity
+            gid = np.asarray(st.rec_gid)
+            gmap = np.asarray(shards[j].global_ids, np.int32)
+            slab.data[j - lo, :p, :c] = np.asarray(st.data)
+            slab.norms[j - lo, :p, :c] = np.asarray(st.norms)
+            slab.rec_dfs[j - lo, :p, :c] = np.asarray(st.rec_dfs)
+            slab.rec_gid[j - lo, :p, :c] = np.where(
+                gid >= 0, gmap[np.maximum(gid, 0)], -1)
+            slab.count[j - lo, :p] = np.asarray(st.count)
+        for out, x in zip(pieces, slab):
+            out.append(jax.device_put(x, dev))
+    return PartitionStore(*[
+        jax.make_array_from_single_device_arrays(
+            (s_pad,) + arrs[0].shape[1:], sharding, arrs)
+        for arrs in pieces])
+
+
+def _slab_store(data, norms, rdfs, rgid, count) -> PartitionStore:
+    """A device's resident shards ``[per, P, ...]`` as ONE store of
+    ``per·P`` partitions (a free reshape): shard ``j``'s partition ``p``
+    is ``j·P + p`` (:func:`_offset_plan`), so every shard refines straight
+    out of the slab — a ``data[j]`` operand would be a copy of the shard."""
+    flat = lambda x: x.reshape(x.shape[0] * x.shape[1], *x.shape[2:])
+    return PartitionStore(flat(data), flat(norms), flat(rdfs), flat(rgid),
+                          flat(count))
+
+
+def _offset_plan(sel_part, j: int, num_partitions: int):
+    """Shard ``j``'s plan partition ids in its device slab (pads stay -1)."""
+    return jnp.where(sel_part >= 0, sel_part + j * num_partitions, -1)
 
 
 class MeshFleetPlacement:
@@ -90,16 +149,10 @@ class MeshFleetPlacement:
         self.data_axis = data_axis
         self.num_shards = len(shards)
         n_dev = mesh.shape[data_axis]
-        stacked = stack_stores([s.index.store for s in shards],
-                               [s.global_ids for s in shards])
-        stacked = pad_store(stacked, n_dev)       # ragged S % n_dev
-        self.num_slots = int(stacked.data.shape[0])   # S_pad
-        specs = store_pspecs(data_axis)
+        self.num_slots = -(-len(shards) // n_dev) * n_dev   # S_pad
         shard_put = lambda x: jax.device_put(
             x, NamedSharding(mesh, PS(data_axis)))
-        self.store = PartitionStore(*[
-            jax.device_put(x, NamedSharding(mesh, s))
-            for x, s in zip(stacked, specs)])
+        self.store = _lay_out_stores(mesh, data_axis, shards, self.num_slots)
 
         # ---- device-resident planning inputs (uniform-cfg fleets) -------
         self._indexes = [s.index for s in shards]
@@ -174,8 +227,6 @@ class MeshFleetPlacement:
 
     def _build_query(self, variant: str, k: int, use_kernel: bool, b: int):
         """Compile the fused featurize→descend→plan→refine→merge pass."""
-        from jax.experimental.shard_map import shard_map
-
         axis = self.data_axis
         n_dev = self.mesh.shape[axis]
         per = self.num_slots // n_dev
@@ -194,11 +245,9 @@ class MeshFleetPlacement:
             # profiler traces (see repro.obs.profile)
             with jax.named_scope("climber.featurize"):
                 z = _paa(q, w)                     # shard-independent
+            slab = _slab_store(data, norms, rdfs, rgid, count)
             d_l, g_l, sp_l, lo_l, hi_l, pt_l, sc_l = ([] for _ in range(7))
             for j in range(per):                   # static unroll
-                st = PartitionStore(data=data[j], norms=norms[j],
-                                    rec_dfs=rdfs[j], rec_gid=rgid[j],
-                                    count=count[j])
                 with jax.named_scope("climber.plan"):
                     p4r = sig_mod.rank_signature(z, piv[j], m)
                     trie = trie_row(tab, j, num_pivots=r,
@@ -222,11 +271,13 @@ class MeshFleetPlacement:
                     # metrics from the unmasked plan — the host loop
                     # computes them per shard before the routing mask
                     pt_l.append(qp_b.partitions_touched())
-                    sc_l.append(candidates_scanned(qp_b, st))
+                    sc_l.append(candidates_scanned(
+                        qp_b, slab._replace(count=count[j])))
                 with jax.named_scope("climber.refine"):
                     spm = jnp.where(routed[j][:, None], sp, -1)
-                    d, g = refine(st, q, spm, lo, hi, k,
-                                  use_kernel=use_kernel)
+                    d, g = refine(slab, q,
+                                  _offset_plan(spm, j, data.shape[1]),
+                                  lo, hi, k, use_kernel=use_kernel)
                 d_l.append(d)
                 g_l.append(g)
                 sp_l.append(sp)
@@ -249,14 +300,14 @@ class MeshFleetPlacement:
             return (best_d, best_g, jnp.stack(sp_l), jnp.stack(lo_l),
                     jnp.stack(hi_l), jnp.stack(pt_l), jnp.stack(sc_l))
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn, mesh=self.mesh,
             in_specs=(PS(axis), PS(axis), PS(axis), PS(axis), PS(axis),
                       PS(axis), PS(axis), PS(axis), PS(axis),
                       PS(), PS(axis)),
             out_specs=(PS(), PS(), PS(axis), PS(axis), PS(axis),
                        PS(axis), PS(axis)),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(fn)
 
     def query(self, queries: np.ndarray, routed: np.ndarray, k: int, *,
@@ -306,8 +357,6 @@ class MeshFleetPlacement:
     # ------------------------------------------------------------------
     def _build_dispatch(self, k: int, use_kernel: bool):
         """Compile the single-collective fan-out for one (shapes, k) combo."""
-        from jax.experimental.shard_map import shard_map
-
         axis = self.data_axis
         n_dev = self.mesh.shape[axis]
         per = self.num_slots // n_dev
@@ -317,13 +366,12 @@ class MeshFleetPlacement:
             # data: [per, P, cap, n] — this device's resident shards;
             # sp/lo/hi: [per, Q, MP] — their (routing-masked) plans.
             local_d, local_g = [], []
+            slab = _slab_store(data, norms, rdfs, rgid, count)
             with jax.named_scope("climber.refine"):
                 for j in range(per):                 # static unroll
-                    st = PartitionStore(data=data[j], norms=norms[j],
-                                        rec_dfs=rdfs[j], rec_gid=rgid[j],
-                                        count=count[j])
-                    d, g = refine(st, q, sp[j], lo[j], hi[j], k,
-                                  use_kernel=use_kernel)
+                    d, g = refine(slab, q,
+                                  _offset_plan(sp[j], j, data.shape[1]),
+                                  lo[j], hi[j], k, use_kernel=use_kernel)
                     local_d.append(d)
                     local_g.append(g)
             with jax.named_scope("climber.merge"):
@@ -343,12 +391,12 @@ class MeshFleetPlacement:
                                                 d_all[s], g_all[s], k)
             return best_d, best_g
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn, mesh=self.mesh,
             in_specs=(PS(axis), PS(axis), PS(axis), PS(axis), PS(axis),
                       PS(), PS(axis), PS(axis), PS(axis)),
             out_specs=(PS(), PS()),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(fn)
 
     def dispatch(self, queries: np.ndarray, sel_part: np.ndarray,

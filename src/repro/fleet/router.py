@@ -44,6 +44,8 @@ from repro.core.signatures import (decay_weights, rank_signature,
                                    weighted_onehot)
 from repro.utils.config import ClimberConfig
 
+SUMMARY_ROWS = 1 << 16      # records per profile pass of summarize()
+
 
 class SignatureRouter:
     """Scores query signature profiles against per-shard pivot summaries."""
@@ -80,8 +82,16 @@ class SignatureRouter:
         return np.asarray(prof)
 
     def summarize(self, series: np.ndarray) -> np.ndarray:
-        """One shard's pivot summary: its records' mean profile, normalized."""
-        prof = self.signature_profile(series).sum(axis=0)
+        """One shard's pivot summary: its records' mean profile, normalized.
+
+        Profiles are summed ``SUMMARY_ROWS`` records at a time: one pass
+        holds a ``[rows, m, r]`` one-hot on the device, which for a whole
+        2,000,000-series shard would not fit one chip.
+        """
+        prof = sum((self.signature_profile(series[i:i + SUMMARY_ROWS])
+                    .sum(axis=0)
+                    for i in range(0, len(series), SUMMARY_ROWS)),
+                   np.zeros(self.pivots.shape[0], np.float32))
         norm = float(np.linalg.norm(prof))
         return (prof / norm if norm else prof).astype(np.float32)
 

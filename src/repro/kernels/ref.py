@@ -18,7 +18,8 @@ def pairwise_l2_ref(q: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     x = x.astype(jnp.float32)
     q2 = jnp.sum(q * q, axis=-1)[:, None]
     x2 = jnp.sum(x * x, axis=-1)[None, :]
-    return jnp.maximum(q2 - 2.0 * (q @ x.T) + x2, 0.0)
+    qx = jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(q2 - 2.0 * qx + x2, 0.0)
 
 
 def qdots_ref(q: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
@@ -52,7 +53,8 @@ def refine_topk_ref(data, norms, rec_dfs, rec_gid, queries,
     rows = data[pid].astype(jnp.float32)                    # [Q, MP, cap, n]
     d2 = jnp.maximum(
         jnp.sum(q * q, axis=-1)[:, None, None]
-        - 2.0 * jnp.einsum("qn,qmcn->qmc", q, rows)
+        - 2.0 * jnp.einsum("qn,qmcn->qmc", q, rows,
+                           precision=jax.lax.Precision.HIGHEST)
         + norms[pid], 0.0)
     rdfs, rgid = rec_dfs[pid], rec_gid[pid]                 # [Q, MP, cap]
 

@@ -82,8 +82,6 @@ def lower_build_step(multi_pod: bool):
     all-gather).  Manual sharding pins every intermediate to the record
     shard; the step is embarrassingly parallel with zero collectives.
     """
-    from jax.experimental.shard_map import shard_map
-
     mesh, axes = _mesh_and_axes(multi_pod)
     forest, trie, onehot = synthetic_skeleton(CFG)
     data = jax.ShapeDtypeStruct((N_SERIES, CFG.series_len), jnp.float32)
@@ -95,10 +93,10 @@ def lower_build_step(multi_pod: bool):
         return _route_full_dataset(x, pivots, onehot, trie, CFG)
 
     def step(x):
-        return shard_map(local_route, mesh=mesh,
-                         in_specs=PS(axes, None),
-                         out_specs=(PS(axes), PS(axes)),
-                         check_rep=False)(x)
+        return jax.shard_map(local_route, mesh=mesh,
+                             in_specs=PS(axes, None),
+                             out_specs=(PS(axes), PS(axes)),
+                             check_vma=False)(x)
 
     jitted = jax.jit(step, in_shardings=(data_sh,),
                      out_shardings=(out_sh, out_sh))
@@ -107,8 +105,6 @@ def lower_build_step(multi_pod: bool):
 
 def lower_query_step(multi_pod: bool):
     """§VI at scale: plan + sharded masked-ED refine + top-k merge."""
-    from jax.experimental.shard_map import shard_map
-
     mesh, axes = _mesh_and_axes(multi_pod)
     forest, trie, onehot = synthetic_skeleton(CFG)
     n_dev = mesh.devices.size
@@ -159,11 +155,11 @@ def lower_query_step(multi_pod: bool):
             neg, idx = jax.lax.top_k(-d, CFG.k)
             return -neg, jnp.take_along_axis(g, idx, axis=-1)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(PS(axes), PS(axes), PS(axes), PS(axes), PS(axes),
                       PS(), plan_spec, plan_spec, plan_spec),
-            out_specs=(PS(), PS()), check_rep=False)
+            out_specs=(PS(), PS()), check_vma=False)
         return fn(store.data, store.norms, store.rec_dfs, store.rec_gid,
                   store.count, queries, plan.sel_part, plan.sel_lo,
                   plan.sel_hi)

@@ -115,7 +115,6 @@ def _flash_decode_sharded(q: jnp.ndarray, cache_k: jnp.ndarray,
     q: [B, 1, H, hd]; cache_k/v: [B, S, KV, hd] (S sharded over `model`);
     valid: [B, S].  Returns [B, 1, H, hd].
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     mesh, ba = DECODE_SHARD
@@ -145,14 +144,14 @@ def _flash_decode_sharded(q: jnp.ndarray, cache_k: jnp.ndarray,
         out = acc / jnp.maximum(denom[..., None], 1e-30)
         return out.reshape(bq, 1, h, hd).astype(qf.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(PS(bspec, None, None, None),      # q replicated over model
                   PS(bspec, "model", None, None),   # cache: seq-sharded
                   PS(bspec, "model", None, None),
                   PS(bspec, "model")),
         out_specs=PS(bspec, None, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, cache_k, cache_v, valid)
 
 

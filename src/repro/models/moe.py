@@ -107,7 +107,6 @@ def moe_apply(p, x: jnp.ndarray, cfg: ModelConfig, *,
         return moe_local(p, x.reshape(-1, d), cfg,
                          capacity_factor).reshape(b, s, d)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     def local_fn(p_l, x_l):
@@ -127,6 +126,6 @@ def moe_apply(p, x: jnp.ndarray, cfg: ModelConfig, *,
                         "s_up": PS(None, model_axis),
                         "s_down": PS(model_axis, None)})
     x_spec = PS(batch_axes, None, None)
-    fn = shard_map(local_fn, mesh=mesh, in_specs=(p_specs, x_spec),
-                   out_specs=x_spec, check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=(p_specs, x_spec),
+                       out_specs=x_spec, check_vma=False)
     return fn(p, x)

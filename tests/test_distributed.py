@@ -213,7 +213,6 @@ class TestElasticity:
 class TestCompressedAllReduce:
     def test_cross_pod_ef_allreduce(self):
         out = run_subprocess("""
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from repro.distributed.compression import (ef_allreduce_tree,
                                                        init_error_tree)
@@ -226,8 +225,8 @@ class TestCompressedAllReduce:
             def f(g, e):
                 return ef_allreduce_tree({"w": g}, {"w": e}, "pod")
 
-            fn = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                           out_specs=(P("pod"), P("pod")), check_rep=False)
+            fn = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                               out_specs=(P("pod"), P("pod")), check_vma=False)
             red, err = fn(g_global, jnp.zeros((8, 256)))
             got = np.asarray(red["w"])[0]
             rel = float(np.abs(got - true_mean).max()

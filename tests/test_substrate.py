@@ -169,7 +169,6 @@ class TestCompression:
     def test_error_feedback_mean_preserved_over_steps(self):
         """EF accumulates: the *running sum* of compressed reductions tracks
         the running sum of true means (the EF-SGD guarantee)."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_mesh
         if jax.device_count() < 1:
@@ -182,8 +181,8 @@ class TestCompression:
         def f(g, e):
             return ef_allreduce_tree(g, e, "pod")
 
-        fn = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                       out_specs=(P("pod"), P("pod")), check_rep=False)
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                           out_specs=(P("pod"), P("pod")), check_vma=False)
         total_reduced = jnp.zeros(64)
         for _ in range(10):
             red, err = fn(grads, err)
