@@ -50,9 +50,13 @@ Placement — where the sealed shards execute:
     ``FleetQueryInfo.plan_cache_hits`` / ``plan_cache_misses`` report on
     both placements identically.
 
-Observability: every query opens a ``fleet.query`` span with
-``fleet.plan`` / ``fleet.refine`` / ``fleet.merge`` children (per sealed
-shard on the host loop, per device program on the mesh), ingest opens
+Observability: every query opens a ``fleet.query`` span with a
+``fleet.route`` child (the router's call) and ``fleet.plan`` /
+``fleet.refine`` / ``fleet.merge`` children per sealed shard on the host
+loop; on the mesh the children are ``fleet.plan_cache`` (probe and put)
+and the device call's own ``fleet.mesh.query`` (or
+``fleet.mesh.dispatch``) with ``fleet.mesh.put / wait / fetch``, then
+``fleet.merge`` for the delta.  Ingest opens
 ``fleet.insert → wal.append / delta.scatter``, and the background
 compactor opens ``compact.seal → compact.build / compact.swap`` on its
 worker thread — see ``repro.obs`` and docs/OBSERVABILITY.md.  Call
@@ -1093,7 +1097,7 @@ class IndexFleet:
         routed_t = np.zeros((pl.num_slots, qn), dtype=bool)
         routed_t[: len(shards)] = mask.T
         cache = self._plan_cache
-        with TRACER.span("fleet.plan", path="mesh") as sp_plan:
+        with TRACER.span("fleet.plan_cache") as sp_plan:
             keys = [(epoch, variant, queries[i].tobytes())
                     for i in range(qn)]
             rows = [cache.get(kk) for kk in keys]
@@ -1110,20 +1114,20 @@ class IndexFleet:
                         sc_all[:, i] = r
                 spm = np.where(routed_t[:, :, None], sp, -1)
         stage["plan_ms"] += sp_plan.duration_ms
+        # the mesh calls carry their own spans (fleet.mesh.dispatch /
+        # fleet.mesh.query); refine_ms is a clock read around them
+        t_ref = time.perf_counter()
         if all_hit:
-            with TRACER.span("fleet.refine", path="mesh") as sp_ref:
-                dist, gid = pl.dispatch(queries, spm, lo, hi, k,
-                                        use_kernel=use_kernel)
-            stage["refine_ms"] += sp_ref.duration_ms
+            dist, gid = pl.dispatch(queries, spm, lo, hi, k,
+                                    use_kernel=use_kernel)
+            stage["refine_ms"] += (time.perf_counter() - t_ref) * 1e3
         else:
             # the fused pass plans on device, inseparably from refine
-            with TRACER.span("fleet.refine", path="mesh",
-                             fused=True) as sp_ref:
-                dist, gid, sp, lo, hi, pt_all, sc_all = pl.query(
-                    queries, routed_t, k, variant=variant,
-                    use_kernel=use_kernel)
-            stage["refine_ms"] += sp_ref.duration_ms
-            with TRACER.span("fleet.plan", path="mesh") as sp_put:
+            dist, gid, sp, lo, hi, pt_all, sc_all = pl.query(
+                queries, routed_t, k, variant=variant,
+                use_kernel=use_kernel)
+            stage["refine_ms"] += (time.perf_counter() - t_ref) * 1e3
+            with TRACER.span("fleet.plan_cache") as sp_put:
                 for i, kk in enumerate(keys):
                     cache.put(kk, (sp[:, i], lo[:, i], hi[:, i],
                                    pt_all[:, i].astype(np.int64),
@@ -1307,11 +1311,13 @@ class IndexFleet:
                         th = self.router.threshold
                     if th is None:
                         th = self.cfg.routing_threshold
-                    mask = self.router.route_adaptive(
-                        queries, float(th), max_fanout=fanout)
+                    with TRACER.span("fleet.route"):
+                        mask = self.router.route_adaptive(
+                            queries, float(th), max_fanout=fanout)
                 else:
-                    mask = self.router.route(queries,
-                                             fanout or self.cfg.fanout)
+                    with TRACER.span("fleet.route"):
+                        mask = self.router.route(queries,
+                                                 fanout or self.cfg.fanout)
 
             if s:
                 if placement == "mesh":

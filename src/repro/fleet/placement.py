@@ -65,6 +65,7 @@ from repro.core.query import (QueryPlan, ShardPlanContext, candidates_scanned,
 from repro.core.refine import (PAD_DIST, merge_topk, refine,
                                resolve_use_kernel)
 from repro.fleet.device_plan import ShardView, stack_tries, trie_row
+from repro.obs import TRACER
 
 
 def _lay_out_stores(mesh, data_axis: str, shards,
@@ -241,8 +242,8 @@ class MeshFleetPlacement:
             # data…count: [per, ...] this device's resident shards;
             # tab/piv/cent/t_real: their stacked skeletons + planner inputs;
             # routed: [per, Q] fan-out mask.  Queries are replicated.
-            # named_scope markers label the fused stages on captured
-            # profiler traces (see repro.obs.profile)
+            # named_scope markers label the fused stages in the HLO's op
+            # metadata, which a TPU trace does not carry (repro.obs.profile)
             with jax.named_scope("climber.featurize"):
                 z = _paa(q, w)                     # shard-independent
             slab = _slab_store(data, norms, rdfs, rgid, count)
@@ -345,12 +346,14 @@ class MeshFleetPlacement:
             fn = self._query[key] = self._build_query(variant, k,
                                                       use_kernel, b)
         st = self.store
-        with jax.profiler.TraceAnnotation("fleet.mesh.query"):
-            outs = fn(st.data, st.norms, st.rec_dfs, st.rec_gid, st.count,
-                      self.tables, self.pivots, self.centroids, self.t_real,
-                      jnp.asarray(queries, jnp.float32),
-                      jnp.asarray(routed, bool))
-            return tuple(np.asarray(o) for o in outs)
+        with TRACER.span("fleet.mesh.query"):
+            with TRACER.span("fleet.mesh.put"):
+                outs = fn(st.data, st.norms, st.rec_dfs, st.rec_gid,
+                          st.count, self.tables, self.pivots,
+                          self.centroids, self.t_real,
+                          jnp.asarray(queries, jnp.float32),
+                          jnp.asarray(routed, bool))
+            return _fetch(outs)
 
     # ------------------------------------------------------------------
     # refine-only fan-out (host-computed / cache-replayed plans)
@@ -425,10 +428,21 @@ class MeshFleetPlacement:
         if fn is None:
             fn = self._dispatch[key] = self._build_dispatch(k, use_kernel)
         st = self.store
-        with jax.profiler.TraceAnnotation("fleet.mesh.dispatch"):
-            d, g = fn(st.data, st.norms, st.rec_dfs, st.rec_gid, st.count,
-                      jnp.asarray(queries, jnp.float32),
-                      jnp.asarray(sel_part, jnp.int32),
-                      jnp.asarray(sel_lo, jnp.int32),
-                      jnp.asarray(sel_hi, jnp.int32))
-            return np.asarray(d), np.asarray(g)
+        with TRACER.span("fleet.mesh.dispatch"):
+            with TRACER.span("fleet.mesh.put"):
+                outs = fn(st.data, st.norms, st.rec_dfs, st.rec_gid,
+                          st.count, jnp.asarray(queries, jnp.float32),
+                          jnp.asarray(sel_part, jnp.int32),
+                          jnp.asarray(sel_lo, jnp.int32),
+                          jnp.asarray(sel_hi, jnp.int32))
+            return _fetch(outs)
+
+
+def _fetch(outs) -> tuple:
+    """The host side of a mesh call's end: wait for the device, then copy
+    every output to numpy, each in a span of its own (``np.asarray``
+    would block too, so the wait adds no synchronisation)."""
+    with TRACER.span("fleet.mesh.wait"):
+        outs = jax.block_until_ready(outs)
+    with TRACER.span("fleet.mesh.fetch"):
+        return tuple(np.asarray(o) for o in outs)

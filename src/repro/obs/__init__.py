@@ -19,10 +19,10 @@ from repro.obs.tracer import Span, SpanTracer, TraceContext, TRACER
 from repro.obs.flight import FlightRecorder, FLIGHT
 from repro.obs.sentinel import RecallSentinel
 from repro.obs.export import snapshot, spans_jsonl, to_prometheus
-from repro.obs.profile import device_trace, trace_annotation
+from repro.obs.profile import device_trace, install_compile_counter
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
            "Span", "SpanTracer", "TraceContext", "TRACER",
            "FlightRecorder", "FLIGHT", "RecallSentinel",
            "snapshot", "spans_jsonl", "to_prometheus",
-           "device_trace", "trace_annotation"]
+           "device_trace", "install_compile_counter"]

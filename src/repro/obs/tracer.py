@@ -1,8 +1,8 @@
 """Low-overhead span tracer — nested wall-time spans in a bounded ring.
 
 A **span** is one named host-side wall-clock interval with parent/child
-nesting: the query path opens ``serve.tick → fleet.query → fleet.plan /
-fleet.refine / fleet.merge``, ingest opens ``fleet.insert → wal.append /
+nesting: the query path opens ``serve.tick → fleet.query → fleet.route →
+fleet.plan / fleet.refine / fleet.merge``, ingest opens ``fleet.insert → wal.append /
 delta.scatter``, and the background compactor (its own thread) opens
 ``compact.seal → compact.build / compact.swap``.  Finished spans land in
 a bounded ring buffer (old spans fall off; tracing never grows without
@@ -38,10 +38,16 @@ server adopts it at admission and again on the executor thread — so one
 trace links ``net.rtt → net.admit → serve.tick → fleet.query →
 per-shard refine/merge`` across threads and across the socket.
 
+**On the profiler's clock** — every span also opens a
+``jax.profiler.TraceAnnotation`` of its bare name for its duration, so a
+captured device trace carries the same host intervals as the ring, on the
+device trace's own clock.  The annotation is a no-op unless a profiler
+session is active; a process that never imported jax gets none.
+
 Overhead per span: two ``perf_counter`` calls, one dict, one deque
-append, one histogram observe — nanoseconds against the
-hundreds-of-microseconds stages it wraps (the bench-smoke acceptance
-budget is ≤5% on the fleet qps cell; measured well under).
+append, one histogram observe and the annotation's enter/exit.
+PERF.md gives the cost measured on a TPU v5e host, with the profiler
+off and on.
 
 ``TRACER`` is the process default, bound to the default registry.
 """
@@ -50,10 +56,11 @@ from __future__ import annotations
 import itertools
 import json
 import random
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
@@ -103,6 +110,21 @@ class Span:
                 "thread": self.thread, "attrs": self.attrs}
 
 
+_ANNOTATION = None                   # jax.profiler.TraceAnnotation, once seen
+
+
+def _annotation(name: str):
+    """A profiler annotation of ``name``, or a null context where jax has
+    not been imported (no profiler session can be active then)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return nullcontext()
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name)
+
+
 class _Anchor:
     """A context adopted onto a thread's stack — parents like a span but
     is never recorded (the real parent lives on another thread/process)."""
@@ -144,7 +166,8 @@ class SpanTracer:
     def span(self, name: str, **attrs):
         """Open a nested span; yields the live :class:`Span` (its
         ``duration_ms`` is final after the block exits, so callers can
-        reuse the measurement instead of timing twice)."""
+        reuse the measurement instead of timing twice).  The span is also
+        a profiler annotation named ``name`` (attrs are not part of it)."""
         stack = self._stack()
         sid = next(self._ids)
         parent = stack[-1] if stack else None
@@ -155,7 +178,8 @@ class SpanTracer:
                   thread=threading.current_thread().name, attrs=attrs)
         stack.append(sp)
         try:
-            yield sp
+            with _annotation(name):
+                yield sp
         finally:
             sp.end = time.perf_counter()
             stack.pop()

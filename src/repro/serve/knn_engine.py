@@ -45,6 +45,7 @@ import time
 import warnings
 import weakref
 from collections import OrderedDict
+from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -55,7 +56,8 @@ from repro.core.index import ClimberIndex
 from repro.core.query import candidates_scanned, default_slot_budget, \
     get_planner, plan as plan_queries
 from repro.core.refine import dispatch_refine, resolve_use_kernel
-from repro.obs import REGISTRY, TRACER
+from repro.obs import REGISTRY, TRACER, install_compile_counter
+from repro.obs.profile import lowerings
 from repro.obs.tracer import TraceContext
 from repro.serve import api
 
@@ -253,6 +255,9 @@ class BatchedServingLoop:
                                                loop=self.obs_label)
         self.queue_gauge = REGISTRY.gauge("serve.queue_depth",
                                           loop=self.obs_label)
+        self.admit_wait_hist = REGISTRY.histogram("serve.admit_wait_ms",
+                                                  loop=self.obs_label)
+        install_compile_counter()
         # pull-based stats exposure: the collector holds only a weakref,
         # so EngineStats keeps its exact dataclass shape (snapshot() keys
         # are asserted by tier-1 tests) and dead loops unregister alone
@@ -270,10 +275,12 @@ class BatchedServingLoop:
         REGISTRY.add_collector(_collect, loop=self.obs_label)
 
     def reset_metrics(self) -> None:
-        """Zero this loop's aggregate stats and latency histogram (the
-        benchmarks call it between warmup and the timed window)."""
+        """Zero this loop's aggregate stats and its latency and admission-
+        wait histograms (the benchmarks call it between warmup and the
+        timed window)."""
         self.stats = EngineStats()
         self.latency_hist.reset()
+        self.admit_wait_hist.reset()
 
     def capture_device_trace(self, log_dir):
         """Opt-in ``jax.profiler`` capture of everything this loop runs
@@ -283,6 +290,33 @@ class BatchedServingLoop:
 
     def _execute(self, qbatch: np.ndarray, nlive: int):
         raise NotImplementedError
+
+    @contextmanager
+    def _tick_span(self, live: int, tickets=None, **attrs):
+        """The ``serve.tick`` span of one executed batch.
+
+        For queued ``tickets`` it observes each one's admission wait into
+        ``serve.admit_wait_ms``: from ``QueryTicket.submitted_at`` (set
+        when the request was admitted) to the start of this tick, with
+        the largest as the span's ``wait_ms_max`` attr.  A client's
+        ``RETRY_LATER`` back-off happens before admission and is not in
+        it.  The ``lowered`` attr counts the programs JAX lowered during
+        the tick (``jax.lowerings``): a tick that recompiled shows it.
+        """
+        counter = lowerings()
+        lowered0 = counter.value
+        with TRACER.span("serve.tick", loop=self.obs_label, live=live,
+                         **attrs) as tick:
+            if tickets is not None:
+                waits = [(tick.start - t.submitted_at) * 1e3
+                         for t in tickets]
+                tick.attrs["wait_ms_max"] = max(waits, default=0.0)
+                for w in waits:
+                    self.admit_wait_hist.observe(w)
+            try:
+                yield tick
+            finally:
+                tick.attrs["lowered"] = counter.value - lowered0
 
     def _after_tick(self) -> None:
         """Hook run after each completed queue tick (between batches — off
@@ -429,8 +463,8 @@ class BatchedServingLoop:
         """
         ctx, ntraces = self._batch_context(tickets)
         with TRACER.adopt(ctx):
-            with TRACER.span("serve.tick", loop=self.obs_label,
-                             live=len(tickets), traces=ntraces) as tick:
+            with self._tick_span(len(tickets), tickets,
+                                 traces=ntraces) as tick:
                 dist, gid, touched, scanned, dt = \
                     self._execute(qbatch, len(tickets))
             self._finish_batch(tickets, dist, gid, touched, scanned, dt,
@@ -498,8 +532,7 @@ class BatchedServingLoop:
         # pop only after the tick succeeds: a device error leaves the
         # queue intact instead of dropping in-flight requests
         with TRACER.adopt(ctx):
-            with TRACER.span("serve.tick", loop=self.obs_label,
-                             live=len(live), traces=ntraces) as tick:
+            with self._tick_span(len(live), live, traces=ntraces) as tick:
                 dist, gid, touched, scanned, dt = \
                     self._execute(qbatch, len(live))
             del self.queue[:len(live)]
@@ -542,8 +575,7 @@ class BatchedServingLoop:
             if pad:
                 chunk = np.concatenate(
                     [chunk, np.zeros((pad, chunk.shape[1]), np.float32)])
-            with TRACER.span("serve.tick", loop=self.obs_label,
-                             live=nlive):
+            with self._tick_span(nlive):    # no queue: no admission wait
                 dist, gid, touched, scanned, dt = \
                     self._execute(chunk, nlive)
             for _ in range(nlive):           # direct API: no queue wait

@@ -402,6 +402,7 @@ class TestFleetIntegration:
         # the full admission → plan → refine → merge path, one tree
         assert {"serve.tick", "fleet.query", "fleet.plan", "fleet.refine",
                 "fleet.merge"} <= names
+        assert "fleet.route" not in names     # exhaustive: no router call
         fq = [c for c in tree["children"] if c["name"] == "fleet.query"]
         assert len(fq) == 1 and fq[0]["attrs"]["placement"] == "host"
         # per-query latency histogram observed one row per live request
