@@ -32,6 +32,7 @@ of the true answers reaches a recall target).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import List, Optional
 
 import jax
@@ -45,6 +46,20 @@ from repro.core.signatures import (decay_weights, rank_signature,
 from repro.utils.config import ClimberConfig
 
 SUMMARY_ROWS = 1 << 16      # records per profile pass of summarize()
+
+
+@partial(jax.jit, static_argnames=("segments", "m"))
+def profile_batch(series: jax.Array, pivots: jax.Array,
+                  weights: jax.Array, *, segments: int,
+                  m: int) -> jax.Array:
+    """``[N, r]`` decay-weighted P4→ profile of ``series`` ``[N, n]`` under
+    ``pivots`` ``[r, w]``, as one program: ``paa`` → ``rank_signature`` →
+    ``weighted_onehot``.  The pivots and weights are arguments, so a router
+    restored from a snapshot or rebuilt from a new sample reuses the
+    compiled program of each batch shape."""
+    z = paa(series.astype(jnp.float32), segments)
+    p4r = rank_signature(z, pivots, m)
+    return weighted_onehot(p4r, pivots.shape[0], weights)
 
 
 class SignatureRouter:
@@ -74,19 +89,18 @@ class SignatureRouter:
 
     # -- profiles ---------------------------------------------------------
     def signature_profile(self, series: np.ndarray) -> np.ndarray:
-        """``[N, r]`` decay-weighted P4→ profile under the reference pivots."""
-        z = paa(jnp.asarray(series, dtype=jnp.float32),
-                self.cfg.paa_segments)
-        p4r = rank_signature(z, self.pivots, self.cfg.prefix_len)
-        prof = weighted_onehot(p4r, self.pivots.shape[0], self._weights)
-        return np.asarray(prof)
+        """``[N, r]`` decay-weighted P4→ profile under the reference pivots
+        (one launch of the module's compiled :func:`profile_batch`)."""
+        return np.asarray(profile_batch(
+            series, self.pivots, self._weights,
+            segments=self.cfg.paa_segments, m=self.cfg.prefix_len))
 
     def summarize(self, series: np.ndarray) -> np.ndarray:
         """One shard's pivot summary: its records' mean profile, normalized.
 
-        Profiles are summed ``SUMMARY_ROWS`` records at a time: one pass
-        holds a ``[rows, m, r]`` one-hot on the device, which for a whole
-        2,000,000-series shard would not fit one chip.
+        Profiles are summed ``SUMMARY_ROWS`` records at a time, so one
+        pass holds and returns a ``[rows, r]`` profile (52 MB at 65,536
+        rows) and not a whole shard's (1.6 GB at 2,000,000 records).
         """
         prof = sum((self.signature_profile(series[i:i + SUMMARY_ROWS])
                     .sum(axis=0)

@@ -3,10 +3,12 @@
 The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached: what Mosaic refuses here (block shapes off the
 (8, 128) tiling, unsupported vector ops, too much VMEM) would be refused on
-the chip.  Interpret-mode tests cannot see any of it.  Every test compiles
-the served refine path at the paper's widths (n=256, capacity 3000, Q=32,
-K=10 and K=500, the planner's width MP=16 and the exhaustive width MP=P)
-and asserts the program holds the Mosaic kernel (``tpu_custom_call``).
+the chip.  Interpret-mode tests cannot see any of it.  The refine tests
+compile the served refine path at the paper's widths (n=256, capacity 3000,
+Q=32, K=10 and K=500, the planner's width MP=16 and the exhaustive width
+MP=P) and assert the program holds the Mosaic kernel (``tpu_custom_call``);
+the router's signature profile compiles as one program for a batch of 8 and
+for a ``summarize`` chunk.
 
 The topology is described inside a module fixture (never at import): only
 one process at a time may load the TPU library, and the multi-worker test
@@ -126,3 +128,25 @@ def test_mesh_fused_pass_compiles(topo, on_tpu):
     # one shard's store per chip, nothing else of size resident
     per_chip = compiled.memory_analysis().argument_size_in_bytes
     assert per_chip < 2 * P * CAP * N * 4
+
+
+@pytest.mark.parametrize("rows", [8, 1 << 16])
+def test_router_profile_compiles(topo, rows):
+    """The router's signature profile as one program: a batch of 8 queries
+    (routing) and a full chunk of ``summarize``, whose ``[rows, m, r]``
+    one-hot the compiled program does not hold."""
+    from repro.fleet.router import SUMMARY_ROWS, profile_batch
+    from repro.utils.config import ClimberConfig
+    cfg = ClimberConfig()
+    assert rows in (8, SUMMARY_ROWS)
+    one = SingleDeviceSharding(topo.devices[0])
+    f32 = jnp.float32
+    compiled = profile_batch.lower(
+        jax.ShapeDtypeStruct((rows, cfg.series_len), f32, sharding=one),
+        jax.ShapeDtypeStruct((cfg.num_pivots, cfg.paa_segments), f32,
+                             sharding=one),
+        jax.ShapeDtypeStruct((cfg.prefix_len,), f32, sharding=one),
+        segments=cfg.paa_segments, m=cfg.prefix_len).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= rows * cfg.num_pivots * 4
+    assert mem.temp_size_in_bytes < rows * cfg.prefix_len * cfg.num_pivots * 4
