@@ -70,8 +70,10 @@ def _tie(ref_d2: np.ndarray, tol_sq: float) -> float:
 
 
 def judge(corpus: np.ndarray, *, queries, dist, gid, unanswered: int,
-          exh_queries, exh_dist, exh_gid, limits: dict) -> dict:
-    """Run the reference and compare.
+          exh_queries, exh_dist, exh_gid, limits: dict, shards: int = 1,
+          devices=None) -> dict:
+    """Run the reference, scanning ``corpus`` as ``shards`` shards over
+    ``devices`` (:func:`benchlib.reference.exact_topk`), and compare.
 
     Returns ``{"correct", "checks": {name: {"value", "limit"}},
     "recall": [Q] per-query recall of the routed answers}``.
@@ -79,7 +81,8 @@ def judge(corpus: np.ndarray, *, queries, dist, gid, unanswered: int,
     k = dist.shape[1]
     tol_sq = float(limits["dist_err"])
     both_d2, both_ids = reference.exact_topk(
-        corpus, np.concatenate([queries, exh_queries]), k)
+        corpus, np.concatenate([queries, exh_queries]), k, shards=shards,
+        devices=devices)
     nq = len(queries)
     ref_d2, ref_ids = both_d2[:nq], both_ids[:nq]
     ex_d2, ex_ids = both_d2[nq:], both_ids[nq:]

@@ -4,7 +4,10 @@ Imports nothing of the program and takes nothing it made: it scans the
 benchmark's own copy of the corpus.  Squared distances are
 ‖q‖² − 2q·x + ‖x‖² with the dot at float32 (``HIGHEST``: six bf16 passes
 on a TPU), one query block against one corpus chunk at a time, and an
-exact ``top_k`` merged chunk by chunk.
+exact ``top_k`` merged chunk by chunk.  The corpus is scanned one shard's
+rows at a time, each shard on a device of its own where there are as
+many, and chunked within the shard; the shards' tops are merged on the
+host by (distance, id).
 
 ``precision="bf16_3x"`` is the control: the same scan with each dot done
 as three bf16 products (hi·hi + hi·lo + lo·hi, what the TPU's ``HIGH``
@@ -47,9 +50,11 @@ def _dots(q, x, precision: str):
 
 
 def _chunking(n: int) -> int:
+    """The chunk length that splits one shard's ``n`` rows into equal
+    chunks of at most :data:`MAX_CHUNK`."""
     chunks = -(-n // MAX_CHUNK)
     if n % chunks:
-        raise ValueError(f"{n} corpus rows do not split into {chunks} "
+        raise ValueError(f"{n} shard rows do not split into {chunks} "
                          "equal chunks")
     return n // chunks
 
@@ -78,16 +83,30 @@ def _programs(precision: str, kk: int):
 
 
 def exact_topk(corpus: np.ndarray, queries: np.ndarray, k: int, *,
-               precision: str = "highest"):
+               shards: int = 1, devices=None, precision: str = "highest"):
     """``(d2 [Q, k] ascending, ids [Q, k])`` over the whole corpus, ties
-    by the lower row id."""
+    by the lower row id.
+
+    ``corpus`` is ``shards`` equal shards one after another.  Shard ``s``
+    goes to ``devices[s * len(devices) // shards]`` (default: JAX's first
+    device), so with one device per shard every device scans its own
+    shard's rows while the others scan theirs."""
     n = len(corpus)
-    chunk = _chunking(n)
+    per = n // shards
+    if per * shards != n:
+        raise ValueError(f"{n} corpus rows do not split into {shards} "
+                         "equal shards")
+    chunk = _chunking(per)
     kk = min(k, chunk)
     block, merge = _programs(precision, kk)
+    devices = list(devices or jax.devices()[:1])
 
-    x_dev = jax.device_put(np.asarray(corpus, np.float32))
-    xn_dev = jnp.sum(x_dev.astype(jnp.float32) ** 2, axis=-1)
+    held = []                              # (rows, norms, first row, device)
+    for s in range(shards):
+        dev = devices[s * len(devices) // shards]
+        x = jax.device_put(
+            np.asarray(corpus[s * per:(s + 1) * per], np.float32), dev)
+        held.append((x, jnp.sum(x ** 2, axis=-1), s * per, dev))
     qn_total = len(queries)
     out_d = np.empty((qn_total, kk), np.float32)
     out_i = np.empty((qn_total, kk), np.int64)
@@ -95,16 +114,33 @@ def exact_topk(corpus: np.ndarray, queries: np.ndarray, k: int, *,
         qb = np.zeros((QUERY_BLOCK, corpus.shape[1]), np.float32)
         m = min(QUERY_BLOCK, qn_total - lo)
         qb[:m] = queries[lo:lo + m]
-        qj = jnp.asarray(qb)
-        best = None
-        for c in range(0, n, chunk):
-            d, i = block(x_dev[c:c + chunk], xn_dev[c:c + chunk], qj)
-            i = i + c
-            best = (d, i) if best is None else merge(*best, d, i)
-        out_d[lo:lo + m] = np.asarray(best[0])[:m]
-        out_i[lo:lo + m] = np.asarray(best[1])[:m]
-    del x_dev, xn_dev
+        # every shard's scan is dispatched before any result is fetched,
+        # so the devices scan at once
+        tops = []
+        for x, xn, first, dev in held:
+            qj = jax.device_put(qb, dev)
+            best = None
+            for c in range(0, per, chunk):
+                d, i = block(x[c:c + chunk], xn[c:c + chunk], qj)
+                i = i + (first + c)
+                best = (d, i) if best is None else merge(*best, d, i)
+            tops.append(best)
+        d, i = _merge_shards(tops, kk)
+        out_d[lo:lo + m] = d[:m]
+        out_i[lo:lo + m] = i[:m]
+    del held
     return np.maximum(out_d, 0.0), out_i
+
+
+def _merge_shards(tops, kk: int):
+    """The ``kk`` best of the shards' tops by (distance, id)."""
+    d = np.concatenate([np.asarray(t[0]) for t in tops], axis=1)
+    i = np.concatenate([np.asarray(t[1]) for t in tops], axis=1)
+    if len(tops) == 1:
+        return d, i
+    order = np.lexsort((i, d), axis=1)[:, :kk]
+    return (np.take_along_axis(d, order, axis=1),
+            np.take_along_axis(i, order, axis=1))
 
 
 def true_sq_dist(corpus: np.ndarray, queries: np.ndarray,

@@ -1,6 +1,6 @@
 """The system under test, built as the configuration says and driven only
 through its own entry points: ``IndexFleet`` → ``FleetEngine`` →
-``ClimberServer`` on a one-device mesh.
+``ClimberServer`` on a mesh of the cell's chips.
 
 :class:`TickRecorder` is the benchmark's one span: it wraps the engine's
 call into ``IndexFleet.query`` on the fleet instance (no program file is
@@ -70,10 +70,15 @@ def _spanned(span, name: str, fn):
     return call
 
 
-def build(cfg: dict, shards: list):
-    """Build the fleet shard by shard, attach a one-device mesh and wrap it
-    in the engine the configuration describes.  The build's seed is the
-    configuration's, so every run builds the same index."""
+def build(cfg: dict, shards: list, chips: int):
+    """Build the fleet shard by shard, attach a mesh over the cell's
+    ``chips`` and wrap it in the engine the configuration describes.  The
+    build's seed is the configuration's, so every run builds the same
+    index.
+
+    On more than one chip each shard's index is kept on the chip that
+    holds its slot of the mesh layout, so no chip holds another chip's
+    index; on one chip the shards stay on JAX's default device."""
     from repro.fleet import FleetConfig, FleetEngine, IndexFleet
     from repro.launch.mesh import make_mesh
     from repro.serve import api
@@ -81,10 +86,28 @@ def build(cfg: dict, shards: list):
 
     fleet = IndexFleet(FleetConfig(shard_cfg=ClimberConfig(**cfg["climber"]),
                                    **cfg["fleet"]))
-    for s, data in enumerate(shards):
-        fleet.add_shard(f"tenant{s}", data)
-    fleet.attach_mesh(make_mesh((1,), ("data",)))
+    mesh = make_mesh((chips,), ("data",))
+    homes = _slot_devices(mesh, len(shards)) if chips > 1 else \
+        [None] * len(shards)
+    for s, (data, home) in enumerate(zip(shards, homes)):
+        fleet.add_shard(f"tenant{s}", data, device=home)
+    fleet.attach_mesh(mesh)
     return FleetEngine(fleet, config=api.ServingConfig(**cfg["serving"]))
+
+
+def _slot_devices(mesh, num_shards: int) -> list:
+    """The device of each shard's slot when ``num_shards`` shards, padded
+    to a multiple of the mesh's size, are sharded over its ``data`` axis:
+    device d holds the whole slots ``[d * per, (d + 1) * per)``."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    s_pad = -(-num_shards // mesh.size) * mesh.size
+    homes = [None] * num_shards
+    layout = NamedSharding(mesh, PartitionSpec("data"))
+    for dev, idx in layout.devices_indices_map((s_pad,)).items():
+        lo, hi, _ = idx[0].indices(s_pad)
+        for j in range(lo, min(hi, num_shards)):
+            homes[j] = dev
+    return homes
 
 
 def warm_up(engine, queries: np.ndarray):

@@ -29,7 +29,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 WINDOW_SPAN = "bench.window"
 HOST_SPANS = ("bench.tick", "bench.fleet_query", "bench.route",
-              "fleet.mesh.query", "fleet.mesh.dispatch")
+              "serve.tick", "fleet.query", "fleet.route",
+              "fleet.mesh.query", "fleet.mesh.dispatch",
+              "fleet.mesh.put", "fleet.mesh.wait", "fleet.mesh.fetch")
 NO_SPAN = "no tick in flight"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"

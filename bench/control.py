@@ -24,6 +24,7 @@ for _p in (str(ROOT / "src"), str(BENCH)):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+import jax          # noqa: E402
 import numpy as np  # noqa: E402
 
 from benchlib import check, data, reference, spec  # noqa: E402
@@ -32,8 +33,9 @@ CONTROL_PRECISION = "bf16_3x"
 
 
 def control_checks(cfg: dict, traffic: dict, seed: int, queries: int,
-                   seconds: float) -> dict:
-    """The judge's verdict on the control's answers for one seed."""
+                   seconds: float, devices=None) -> dict:
+    """The judge's verdict on the control's answers for one seed, the
+    reference's shards spread over ``devices`` as in a run."""
     n = int(cfg["climber"]["series_len"])
     bs = int(cfg["serving"]["batch_size"])
     k = int(cfg["serving"]["k"])
@@ -43,13 +45,14 @@ def control_checks(cfg: dict, traffic: dict, seed: int, queries: int,
     _, window_q = data.run_queries(cfg, traffic, corpus, seconds, seed)
     issued = window_q[:queries]
     pick = data.exhaustive_pick(seed, np.arange(len(issued)), bs)
+    scan = {"shards": int(cfg["corpus"]["shards"]), "devices": devices}
     d2, ids = reference.exact_topk(corpus, issued, k,
-                                   precision=CONTROL_PRECISION)
+                                   precision=CONTROL_PRECISION, **scan)
     dist, gid = np.sqrt(d2).astype(np.float32), ids.astype(np.int32)
     return check.judge(corpus, queries=issued, dist=dist, gid=gid,
                        unanswered=0, exh_queries=issued[pick],
                        exh_dist=dist[pick], exh_gid=gid[pick],
-                       limits=cfg["limits"])
+                       limits=cfg["limits"], **scan)
 
 
 def main(argv=None) -> int:
@@ -69,9 +72,11 @@ def main(argv=None) -> int:
     if args.rehearse:
         cfg, traffic = spec.rehearsal(cfg, traffic)
     seconds = float(bm["run_seconds"])
+    devices = jax.devices()[:int(cell["chips"])]
     passed = 0
     for seed in (int(s) for s in args.seeds.split(",")):
-        verdict = control_checks(cfg, traffic, seed, args.queries, seconds)
+        verdict = control_checks(cfg, traffic, seed, args.queries, seconds,
+                                 devices)
         passed += verdict["correct"]
         print(json.dumps({"workload": args.workload, "seed": seed,
                           "precision": CONTROL_PRECISION,

@@ -7,13 +7,15 @@
 The cell (``BENCHMARK.json``'s ``workloads``) names a configuration and a
 traffic mix.  A run makes the configuration's corpus on the device from
 the configuration's fixed key and the queries from ``--seed``, builds the
-fleet the configuration describes, serves it with
-``FleetEngine`` behind ``ClimberServer`` on a one-device mesh, warms up
-every batch shape the traffic can form, then lets a separate load
+fleet the configuration describes over the cell's chips (each shard's
+index on the chip that holds its slot of the mesh), serves it with
+``FleetEngine`` behind ``ClimberServer`` on a mesh of those chips, warms
+up every batch shape the traffic can form, then lets a separate load
 generator process drive it over localhost for ``--seconds``.  After the
 window it reads the device's peak memory, asks the same server for an
 exhaustive sample, frees the program, and checks every answer against a
-brute-force reference (``bench/benchlib/check.py``).
+brute-force reference that scans the corpus shard by shard, each shard
+on a chip of its own (``bench/benchlib/check.py``).
 
 With ``--trace 0`` the last line of standard output is the cell's
 end-to-end metrics; with ``--trace 1`` the window runs under the profiler
@@ -24,7 +26,8 @@ and the last key of the line.
 
 No TPU, or fewer chips than the cell asks for: exit 2, no line.
 ``--rehearse`` runs the same path at a tiny size on the CPU (refine kernel
-interpreted) and never prints the metrics line.
+interpreted), with as many CPU devices as the cell has chips, and never
+prints the metrics line.
 """
 import time
 
@@ -33,6 +36,7 @@ T_START = time.monotonic()
 import argparse     # noqa: E402
 import gc           # noqa: E402
 import json         # noqa: E402
+import os           # noqa: E402
 import sys          # noqa: E402
 import tempfile     # noqa: E402
 from pathlib import Path    # noqa: E402
@@ -50,6 +54,7 @@ from benchlib.loadgen import LoadGenerator, OK  # noqa: E402
 
 EXIT_NO_CHIP = 2
 LOWER_SPAN = "jaxpr_to_mlir_module"       # one per program lowered
+HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count"
 
 
 def log(msg: str) -> None:
@@ -86,6 +91,15 @@ class CompileCounter:
         return sum(t0 <= t < t1 for t in self.times)
 
 
+def force_host_devices(count: int) -> None:
+    """Give JAX's CPU backend ``count`` devices, keeping every other flag
+    of ``XLA_FLAGS``.  Takes effect only before JAX is first imported."""
+    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
+             if not f.startswith(HOST_DEVICES_FLAG)]
+    flags.append(f"{HOST_DEVICES_FLAG}={count}")
+    os.environ["XLA_FLAGS"] = " ".join(flags)
+
+
 def device_info(jax, chips: int) -> dict:
     devs = jax.devices()[:chips]
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
@@ -108,6 +122,7 @@ def run(args) -> dict:
     seconds = float(args.seconds or bm["run_seconds"])
     if args.rehearse:
         cfg, traffic = spec.rehearsal(cfg, traffic)
+        force_host_devices(int(cell["chips"]))
     gen = LoadGenerator()           # its imports overlap the set-up below
     try:
         return _run(args, bm, cell, cfg, traffic, seconds, gen)
@@ -152,11 +167,11 @@ def _run(args, bm, cell, cfg, traffic, seconds, gen) -> dict:
         f"{time.monotonic() - t:.1f} s")
 
     t = time.monotonic()
-    engine = serve.build(cfg, shards)
+    engine = serve.build(cfg, shards, chips)
     del shards
     log(f"build: {engine.fleet.total_records:,} series, partitions "
         f"{[sh.index.store.num_partitions for sh in engine.fleet.shards]},"
-        f" in {time.monotonic() - t:.1f} s")
+        f" over {chips} chip(s) in {time.monotonic() - t:.1f} s")
     readers = {m["name"]: spec.load_reader(m["name"])
                for m in (spec.per_layer_for(bm, cell["name"]) if args.trace
                          else spec.end_to_end_for(bm, cell["name"]))}
@@ -211,6 +226,7 @@ def _run(args, bm, cell, cfg, traffic, seconds, gen) -> dict:
         log(f"latency from due (recorded, not judged): p50 {percentile(lat_ms, 50):.3f} ms, p95 "
             f"{percentile(lat_ms, 95):.3f} ms, p99 "
             f"{percentile(lat_ms, 99):.3f} ms")
+    log(f"set-up: {setup_s:.3f} s")
     log(f"compilations inside the window: {in_window}")
     log(f"plan-cache hits inside the window: {hits}")
     log(f"peak device memory: {mem_peak:,} bytes")
@@ -258,7 +274,8 @@ def _run(args, bm, cell, cfg, traffic, seconds, gen) -> dict:
     verdict = check.judge(
         corpus, queries=issued_q[ok], dist=res["dist"][ok],
         gid=res["gid"][ok], unanswered=failed, exh_queries=issued_q[pick],
-        exh_dist=exh_d, exh_gid=exh_g, limits=cfg["limits"])
+        exh_dist=exh_d, exh_gid=exh_g, limits=cfg["limits"],
+        shards=int(cfg["corpus"]["shards"]), devices=jax.devices()[:chips])
     log(f"reference over {int(ok.sum()) + len(pick):,} queries and "
         f"{len(corpus):,} series in {time.monotonic() - t:.1f} s; rms of "
         f"the distance gaps {verdict['dist_rms']:.3e}")

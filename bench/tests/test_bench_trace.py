@@ -42,6 +42,27 @@ def test_reduce_by_hand():
         "jit_f/climber_refine_topk.1"
 
 
+def test_gap_inside_the_mesh_call_lands_on_its_part():
+    """The program's spans inside ``fleet.mesh.query`` take the gaps that
+    open while they do: here the fetch, after the device's work."""
+    ev = {"host": [("bench.window", 0.0, 10.0),
+                   ("serve.tick", 1.0, 6.0),
+                   ("fleet.query", 1.1, 5.9),
+                   ("fleet.route", 1.2, 1.5),
+                   ("fleet.mesh.query", 1.6, 5.8),
+                   ("fleet.mesh.put", 1.7, 2.0),
+                   ("fleet.mesh.wait", 2.0, 4.0),
+                   ("fleet.mesh.fetch", 4.0, 5.6)],
+          "devices": {"/device:TPU:0": [(0.0, 1.2, "jit_f/fusion.1"),
+                                        (1.5, 4.0, "jit_f/fusion.2"),
+                                        (5.7, 10.0, "jit_f/fusion.3")]}}
+    gaps = dict(trace.reduce_events(ev).idle_gaps)
+    # 1.2..1.5 (mid 1.35: the route), 4.0..5.7 (mid 4.85: the fetch)
+    assert gaps == {"fleet.route": pytest.approx(0.3),
+                    "fleet.mesh.fetch": pytest.approx(1.7)}
+    assert "fleet.mesh.query" not in gaps
+
+
 @pytest.mark.parametrize("raw,name,family", [
     ("%climber_refine_topk.1 = (f32[8,1,512]) custom-call(s32[8,124] %a)",
      "climber_refine_topk.1", "climber_refine_topk"),
